@@ -6,18 +6,19 @@ path, on plain and encoded sources.  Wins are reported as
 **fraction-of-roofline**, not just speedups: each row derives
 
     achieved_gbps     = bytes the scan must move / wall time
-    roofline_fraction = achieved_gbps / HBM_BW   (repro.launch.mesh, 819
-                        GB/s — the TPU HBM figure the roofline benchmark
-                        uses; on a CPU host the fraction is honest about
-                        how far interpret mode sits from the roof)
+    roofline_fraction = achieved_gbps / the chip's HBM bandwidth
+                        (repro.launch.mesh.chip_peaks, keyed by the
+                        device kind) — TPU runs only; elsewhere it is
+                        None, "not measured"
 
 and encoded sources score their *physical* bytes — the stream the
 dictionary / bit-packed columns actually move — so the decode-in-kernel
 bandwidth win shows up as the SAME aggregate answer from fewer bytes.
 That byte shrinkage is not read off trustingly: the audit catalog's
 ``bytes_moved`` check is run with ``raise_on_failure=True`` before
-timing, and every fused/encoded result is asserted bitwise-identical to
-the plain scan-path result.
+timing, and every fused/encoded result is checked against the plain
+scan-path result: bitwise where the kernel shares the scan's expression
+tree, within the float64-oracle tolerance where it does not.
 
 Output: CSV to stdout + benchmarks/out/BENCH_fused.json (schema rows in
 benchmarks/README.md; seeded baseline in benchmarks/baselines/).
@@ -42,7 +43,7 @@ from repro.core.spec import QuerySpec
 from repro.data import encodings as ENC
 from repro.data import source as DS
 from repro.data import tpch
-from repro.launch.mesh import HBM_BW
+from repro.launch.mesh import chip_peaks
 
 ROWS = 2_000_000
 SMOKE_ROWS = 400_000
@@ -91,9 +92,12 @@ def _tree_bytes(tree) -> int:
 
 def _roofline(bytes_moved: int, us: float) -> dict:
     gbps = bytes_moved / (us / 1e6) / 1e9
+    dev = jax.devices()[0]
+    frac = (gbps / (chip_peaks(dev.device_kind).hbm_bw / 1e9)
+            if dev.platform == "tpu" else None)
     return {"bytes_moved": bytes_moved,
             "achieved_gbps": gbps,
-            "roofline_fraction": gbps / (HBM_BW / 1e9)}
+            "roofline_fraction": frac}
 
 
 def run(rows=ROWS, repeats=3, out=sys.stdout):
@@ -152,12 +156,17 @@ def run(rows=ROWS, repeats=3, out=sys.stdout):
         # the whole point of bitwise finals: speed claims are apples to
         # apples — same answer, fewer seconds / fewer bytes
         ref = run_scan()
+        tol = 2 * tpch.f32_sum_tolerance(int(shards["_mask"].size))
+        bitwise = {}
         for label, res in (("fused", run_fused()),
                            ("encoded", run_encoded())):
-            for a, b in zip(jax.tree.leaves(res.final),
-                            jax.tree.leaves(ref.final)):
-                assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), (
-                    f"{label} {fam} final differs from the scan path")
+            pairs = [(np.asarray(a), np.asarray(b)) for a, b in zip(
+                jax.tree.leaves(res.final), jax.tree.leaves(ref.final))]
+            bitwise[label] = all(a.tobytes() == b.tobytes() for a, b in pairs)
+            for a, b in pairs:
+                assert np.all(np.abs(a - b) <= tol * np.abs(b)), (
+                    f"{label} {fam} final is outside the f32 tolerance of "
+                    "the scan path")
 
         rows_out = [
             ("scan_" + fam, scan_us, {
@@ -166,18 +175,19 @@ def run(rows=ROWS, repeats=3, out=sys.stdout):
             ("fused_" + fam, fused_us, {
                 "rows": rows, "rounds": ROUNDS,
                 "speedup_vs_scan": scan_us / fused_us,
-                "bitwise_vs_scan": True,
+                "bitwise_vs_scan": bitwise["fused"],
                 **_roofline(logical_bytes, fused_us)}),
             ("encoded_fused_" + fam, enc_us, {
                 "rows": rows, "rounds": ROUNDS,
                 "byte_ratio_vs_logical": byte_ratio,
                 "logical_bytes": logical_bytes,
-                "bitwise_vs_scan": True,
+                "bitwise_vs_scan": bitwise["encoded"],
                 **_roofline(physical_bytes, enc_us)}),
         ]
         for name, us, derived in rows_out:
             frac = derived["roofline_fraction"]
-            print(f"{name},{us:.0f},roofline_frac={frac:.4f}", file=out)
+            frac = "not measured" if frac is None else f"{frac:.4f}"
+            print(f"{name},{us:.0f},roofline_frac={frac}", file=out)
             bench_rows.append({"name": name, "us_per_call": us,
                                "derived": derived})
 

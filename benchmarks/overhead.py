@@ -22,6 +22,7 @@ BENCH_overhead.json (schema in benchmarks/README.md).
 """
 from __future__ import annotations
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -146,7 +147,9 @@ def run(out=sys.stdout, rows=ROWS, sh_repeats=25):
     # machine-load drift cannot masquerade as overhead.  In-process psum
     # latency is tiny compared to a network round-trip, so the measured sync
     # overhead is a *lower bound* on the real barrier cost; the per-chunk
-    # collective count is the mechanism.
+    # collective count is the mechanism.  The child measures that mechanism
+    # on virtual CPU devices, never the accelerator: JAX_PLATFORMS=cpu keeps
+    # it off a chip this process may already hold.
     code = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d"
@@ -182,7 +185,8 @@ def run(out=sys.stdout, rows=ROWS, sh_repeats=25):
     """ % (SH_PARTS, str(SRC), SH_ROWS, SH_PARTS, SH_CHUNK, SH_ROUNDS,
            sh_repeats))
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=900)
+                       text=True, timeout=900,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
     parsed = False
     for line in r.stdout.splitlines():
         if line.startswith("SHARDED"):
@@ -212,7 +216,9 @@ def run(out=sys.stdout, rows=ROWS, sh_repeats=25):
                       "load-independent mechanism", file=out)
             parsed = True
     if not parsed:
-        print(f"# sharded section failed: {r.stderr[-500:]}", file=out)
+        raise RuntimeError(
+            f"sharded overhead child failed (exit {r.returncode}): "
+            f"{r.stderr[-2000:]}")
 
     try:
         from benchmarks import bench_io

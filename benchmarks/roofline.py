@@ -3,7 +3,7 @@ dry-run's compiled artifacts (experiments/dryrun/*.json).
 
     compute term    = HLO_FLOPs / (chips × 197 TFLOP/s bf16)
     memory term     = HLO_bytes / (chips × 819 GB/s HBM)
-    collective term = collective_bytes / (chips × 50 GB/s ICI)
+    collective term = collective_bytes / (chips × 200 GB/s ICI)
 
 HLO_FLOPs/bytes are loop-aware per-device numbers (repro/analysis/hlo_cost)
 multiplied by chip count to match the spec's global convention — the two
@@ -28,7 +28,7 @@ from pathlib import Path
 import numpy as np
 
 from repro.configs import get_config
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import V5E, chip_peaks
 from repro.launch.shapes import SHAPES
 
 DRYRUN_DIR = Path(__file__).resolve().parents[1] / "experiments" / "dryrun"
@@ -93,14 +93,15 @@ def analyze(mesh_kind: str = "single", dir_path=None):
         flops_dev = d["flops_per_device"]
         bytes_dev = d["bytes_per_device"]
         coll_dev = sum(d["collective_bytes_per_device"].values())
-        t_c = flops_dev / PEAK_FLOPS_BF16
-        t_m = bytes_dev / HBM_BW
-        t_n = coll_dev / ICI_BW
+        peaks = chip_peaks(V5E)   # the production mesh's chip
+        t_c = flops_dev / peaks.flops_bf16
+        t_m = bytes_dev / peaks.hbm_bw
+        t_n = coll_dev / peaks.ici_bw
         dom = max(("compute", t_c), ("memory", t_m), ("collective", t_n),
                   key=lambda kv: kv[1])
         mf = model_flops(cfg, shape)
         ratio = mf / (flops_dev * chips) if flops_dev else 0.0
-        frac = (mf / chips / PEAK_FLOPS_BF16) / dom[1] if dom[1] > 0 else 0.0
+        frac = (mf / chips / peaks.flops_bf16) / dom[1] if dom[1] > 0 else 0.0
         out.append({
             "cell": d["cell"], "status": "OK",
             "compute_s": t_c, "memory_s": t_m, "collective_s": t_n,

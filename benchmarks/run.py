@@ -77,6 +77,8 @@ def main(argv=None):
                          "exercises every section and emits every "
                          "BENCH_*.json in minutes")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     smoke = args.smoke
     quick = smoke or args.mode == "quick"
 
@@ -146,22 +148,18 @@ def main(argv=None):
     convergence.run(tasks=tasks, rows=100_000 if smoke else convergence.ROWS)
 
     print("# === roofline (dry-run artifacts) ===")
-    try:
-        from benchmarks import roofline
-        rows = roofline.analyze("single")
-        if not rows:
-            print("no dry-run artifacts; run: python -m repro.launch.dryrun --all")
-        print("name,us_per_call,derived")
-        for r in rows:
-            if r["status"] != "OK":
-                continue
-            dom_s = max(r["compute_s"], r["memory_s"], r["collective_s"])
-            print(f"roofline_{r['cell']},{dom_s * 1e6:.0f},"
-                  f"bottleneck={r['bottleneck']};"
-                  f"fraction={r['roofline_fraction']:.3f}")
-    except Exception as e:  # artifacts absent in fresh checkouts
-        print(f"roofline skipped: {e}")
-
+    from benchmarks import roofline
+    rows = roofline.analyze("single")
+    if not rows:
+        print("no dry-run artifacts; run: python -m repro.launch.dryrun --all")
+    print("name,us_per_call,derived")
+    for r in rows:
+        if r["status"] != "OK":
+            continue
+        dom_s = max(r["compute_s"], r["memory_s"], r["collective_s"])
+        print(f"roofline_{r['cell']},{dom_s * 1e6:.0f},"
+              f"bottleneck={r['bottleneck']};"
+              f"fraction={r['roofline_fraction']:.3f}")
 
 if __name__ == "__main__":
     main()

@@ -110,9 +110,12 @@ def _merge_over_partitions(gla: GLA, states: Pytree, w: jnp.ndarray, merge,
     """
     P = w.shape[0]
     if gla.merge_is_additive:
+        # HIGHEST: at default precision a TPU rounds f32 dot operands to
+        # bf16, which would cut the merged states to 8 mantissa bits
         return jax.tree.map(
-            lambda x: jnp.tensordot(w.astype(x.dtype), x, axes=(0, 0)), states
-        )
+            lambda x: jnp.tensordot(w.astype(x.dtype), x, axes=(0, 0),
+                                    precision=jax.lax.Precision.HIGHEST),
+            states)
     if not all_alive:
         raise NotImplementedError("alive masks need merge_is_additive")
     return SC.fold_merge(merge, states, P)
@@ -125,7 +128,8 @@ def _merge_rounds(gla: GLA, states: Pytree, w_pr: jnp.ndarray, merge,
     if gla.merge_is_additive:
         return jax.tree.map(
             lambda x: jnp.einsum(
-                "pr,pr...->r...", w_pr.astype(x.dtype), x), states
+                "pr,pr...->r...", w_pr.astype(x.dtype), x,
+                precision=jax.lax.Precision.HIGHEST), states
         )
     if not all_alive:
         raise NotImplementedError("alive masks need merge_is_additive")
@@ -144,8 +148,9 @@ def _run_vmapped(gla: GLA, shards: dict, sched: jnp.ndarray, alive: jnp.ndarray,
     d_local = jnp.sum(shards["_mask"], axis=(1, 2))
     d_total = jnp.sum(d_local)
     w_pr, w_final = SC.round_weights(alive, R)
-    # fused dispatch blocks one [1, L] row per column — trailing dims fall
-    # back to the legacy kernels (resident shards are always plain/decoded)
+    # fused dispatch blocks [BLOCK_CHUNKS, L] rows per column — trailing
+    # dims fall back to the legacy kernels (resident shards are always
+    # plain/decoded)
     fused_ok = SC.fused_available(gla) and all(
         v.ndim == 3 for v in shards.values())
 
@@ -161,8 +166,10 @@ def _run_vmapped(gla: GLA, shards: dict, sched: jnp.ndarray, alive: jnp.ndarray,
         # snapshots off: no round states are consumed — one whole-shard
         # dispatch (same chunk-sequential association, R-fold fewer launches)
         if fused_ok:
-            # one fused selection→bucket→aggregate dispatch per round-slice,
-            # bitwise-identical to the scan path (DESIGN.md §12)
+            # one fused selection→bucket→aggregate dispatch per round-slice
+            # (DESIGN.md §12), bitwise-identical to the scan path except
+            # for compiled TPU group members, which match within the
+            # float64-oracle tolerance (docs/KERNELS.md rule 2)
             finals, round_states = SC.fused_rounds_states_batched(
                 gla, shards, R if snapshots else 1)
         else:

@@ -34,9 +34,17 @@ Columns = Dict[str, jnp.ndarray]
 
 
 def randomize_global(cols: Columns, key, num_partitions: int) -> List[Columns]:
-    """Reference: global permutation, then round-robin split into partitions."""
+    """Reference: global permutation, then round-robin split into partitions.
+
+    Device columns are permuted on their device.  Host (NumPy) columns
+    stay on the host: only the permutation is drawn on the default device,
+    and the gather and split run in NumPy — so a load for a mesh never
+    holds the whole dataset on one device.
+    """
     n = next(iter(cols.values())).shape[0]
     perm = jax.random.permutation(key, n)
+    if all(isinstance(v, np.ndarray) for v in cols.values()):
+        perm = np.asarray(perm)
     shuffled = {k: v[perm] for k, v in cols.items()}
     # Contiguous split (equal sizes up to remainder).
     bounds = np.linspace(0, n, num_partitions + 1).astype(int)
@@ -89,13 +97,23 @@ def randomize_distributed(
 
 
 def pack_partitions(
-    parts: List[Columns], chunk_len: int, *, min_chunks: int | None = None
+    parts: List[Columns], chunk_len: int, *, min_chunks: int | None = None,
+    sharding=None,
 ) -> Columns:
     """Pad ragged partitions to [P, C, L] chunked columns with a _mask.
 
     The engine consumes this layout.  ``_mask`` marks live items; padded
     slots never contribute to any GLA state (uda.Chunk contract).
+
+    The columns are packed on the host and then placed: on the default
+    device, or by ``sharding`` — with partitions on a mesh axis
+    (``NamedSharding(mesh, PartitionSpec("data"))``) each device receives
+    only its own partitions, straight from the host buffer.
     """
+    def put(buf):
+        return jnp.asarray(buf) if sharding is None else jax.device_put(
+            buf, sharding)
+
     P = len(parts)
     ns = [next(iter(p.values())).shape[0] for p in parts]
     C = max(-(-n // chunk_len) for n in ns)  # ceil
@@ -109,9 +127,9 @@ def pack_partitions(
         for i, p in enumerate(parts):
             v = np.asarray(p[k])
             buf[i, : v.shape[0]] = v
-        out[k] = jnp.asarray(buf.reshape(P, C, chunk_len))
+        out[k] = put(buf.reshape(P, C, chunk_len))
     mask = np.zeros((P, total), dtype=np.float32)
     for i, n in enumerate(ns):
         mask[i, :n] = 1.0
-    out["_mask"] = jnp.asarray(mask.reshape(P, C, chunk_len))
+    out["_mask"] = put(mask.reshape(P, C, chunk_len))
     return out

@@ -489,7 +489,7 @@ def fused_available(gla: GLA, columns=None) -> bool:
 
 
 def fused_round_step(gla: GLA, state, slice_cols: dict, encodings=(), *,
-                     use_mxu: bool = False):
+                     use_mxu=None):
     """Carry-in fused step for ONE round-slice: (state, slice) -> state.
 
     The per-round-slice primitive behind the ``kernel_fused`` session path.
@@ -500,8 +500,9 @@ def fused_round_step(gla: GLA, state, slice_cols: dict, encodings=(), *,
     (name, Encoding) tuple; encoded columns arrive physical and are decoded
     inside the kernel body.  Join GLAs additionally ship their replicated
     probe tables as extra kernel operands (``FusedSpec.probe_tables``);
-    ``use_mxu`` selects the one-hot matmul group scatter (TPU MXU lowering
-    — re-associates, so allclose rather than bitwise vs the default).
+    ``use_mxu`` picks the group accumulation and defaults to the compile
+    target: the one-hot matmul in a compiled TPU kernel (re-associates, so
+    oracle tolerance rather than bitwise), segment_sum under interpret.
     """
     from repro.kernels import fused_agg
 

@@ -522,7 +522,9 @@ class Session:
             # the fused kernel (DESIGN.md §12) subsumes the legacy kernel
             # paths whenever the GLA publishes a FusedSpec and every column
             # is kernel-shaped; it is carry-style and bitwise-identical to
-            # the scan path, scalar GLAs included.
+            # the scan path, except for group members in a compiled TPU
+            # kernel: those match within the float64-oracle tolerance
+            # (docs/KERNELS.md rule 2).
             if SC.fused_available(gla, self._source.spec.columns):
                 self._path = "kernel_fused"
             else:

@@ -246,6 +246,27 @@ def _exact_batches(cols, batch_rows: int):
         yield chunk
 
 
+F32_UNIT_ROUNDOFF = 2.0 ** -24
+
+
+def f32_sum_tolerance(n_terms: int) -> float:
+    """Relative error bound of a float32 sum over at most ``n_terms``
+    sequential additions, against the float64 oracle.
+
+    Any summation order lands within γ_n·Σ|x| of the exact sum, with
+    γ_n = n·u / (1 − n·u) and u = 2^-24 (Higham, *Accuracy and Stability
+    of Numerical Algorithms*, 2nd ed., §4.2).  Multiply by Σ|x| — the
+    oracle itself when every term is nonnegative — for the absolute
+    tolerance.  Two f32 computations of one sum that associate
+    differently (f32 carries, ROADMAP Reach 2.1) may each sit anywhere
+    inside it.
+    """
+    nu = n_terms * F32_UNIT_ROUNDOFF
+    if nu >= 1:
+        raise ValueError(f"{n_terms} additions exceed float32's error bound")
+    return nu / (1 - nu)
+
+
 def exact_answer(cols, func, cond, group=None,
                  num_groups: int | None = None, *,
                  batch_rows: int = 1 << 18,

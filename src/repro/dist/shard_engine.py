@@ -79,16 +79,11 @@ def device_put_carry(states, *, mesh, axis_name: str = "data"):
 
 
 def _shard_map(worker, mesh, in_specs, out_specs):
-    """jax-version-tolerant shard_map with replication checking off (the
-    scan carry starts replicated from gla.init and becomes device-varying
-    after the first accumulate, which the static checker rejects)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(worker, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as xfn
-    return xfn(worker, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with replication checking off (the scan carry
+    starts replicated from gla.init and becomes device-varying after the
+    first accumulate, which the static checker rejects)."""
+    return jax.shard_map(worker, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @functools.partial(
@@ -102,8 +97,9 @@ def _run_sharded_jit(gla: GLA, shards: dict, sched: jnp.ndarray,
                      sync_cost_model: bool):
     P = shards["_mask"].shape[0]
     R = sched.shape[1] - 1
-    # fused dispatch blocks one [1, L] row per column — trailing dims fall
-    # back to the legacy kernels (resident shards are always plain/decoded)
+    # fused dispatch blocks [BLOCK_CHUNKS, L] rows per column — trailing
+    # dims fall back to the legacy kernels (resident shards are always
+    # plain/decoded)
     fused_ok = SC.fused_available(gla) and all(
         v.ndim == 3 for v in shards.values())
 
@@ -136,8 +132,10 @@ def _run_sharded_jit(gla: GLA, shards: dict, sched: jnp.ndarray,
             assert lanes == 1, "emit='kernel' runs single-lane"
             if fused_ok and (gla.members or gla.kernel_num_groups is not None):
                 # ONE fused selection→bucket→aggregate dispatch per
-                # round-slice covers every member, bitwise-identical to the
-                # scan path (DESIGN.md §12).
+                # round-slice covers every member (DESIGN.md §12),
+                # bitwise-identical to the scan path except for compiled
+                # TPU group members, which match within the float64-oracle
+                # tolerance (docs/KERNELS.md rule 2).
                 final_view, round_states = SC.fused_rounds_states(
                     gla, cols, R if snapshots else 1)
                 prefixes = None

@@ -44,33 +44,17 @@ def mesh_axis_size(mesh, axis: str) -> int:
     i = names.index(axis)
     if hasattr(mesh, "devices"):  # jax.Mesh or test stand-in
         return int(mesh.devices.shape[i])
-    return int(tuple(mesh.axis_sizes)[i])  # AbstractMesh (newer jax)
+    return int(tuple(mesh.axis_sizes)[i])  # AbstractMesh
 
 
 def ambient_mesh():
     """The mesh activations should be pinned against, or None.
 
-    jax-version tolerant: prefers ``jax.sharding.get_abstract_mesh`` (newer
-    jax, set via ``jax.set_mesh``), falls back to the thread-local physical
-    mesh installed by ``with mesh:`` blocks, and returns None when neither
-    is active so model-side pinning helpers become no-ops.
+    That is the mesh installed by ``jax.set_mesh``; with none installed,
+    model-side pinning helpers become no-ops.
     """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        try:
-            m = fn()
-            if m is not None and not m.empty:
-                return m
-        except Exception:
-            pass
-    try:
-        from jax._src import mesh as _mesh_internal
-        pm = _mesh_internal.thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return pm
-    except Exception:
-        pass
-    return None
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def batch_axes(mesh) -> Tuple[str, ...]:

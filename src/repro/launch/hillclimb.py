@@ -19,7 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.analysis import hlo_cost as HC
 from repro.launch import mesh as M
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import V5E, chip_peaks
 
 OUT = Path(__file__).resolve().parents[3] / "experiments" / "hillclimb"
 
@@ -63,9 +63,10 @@ def run(arch: str, shape: str, overrides: dict, label: str,
     hc = HC.HloCost(compiled.as_text())
     tot = hc.total()
     coll = sum(tot.collective_bytes.values())
-    t_c = tot.flops / PEAK_FLOPS_BF16
-    t_m = tot.bytes / HBM_BW
-    t_n = coll / ICI_BW
+    peaks = chip_peaks(V5E)   # the production mesh's chip
+    t_c = tot.flops / peaks.flops_bf16
+    t_m = tot.bytes / peaks.hbm_bw
+    t_n = coll / peaks.ici_bw
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     rec = {

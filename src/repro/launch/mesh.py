@@ -12,6 +12,8 @@ the `pod` axis; model parallelism never leaves a pod.
 """
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 
 
@@ -29,8 +31,30 @@ def make_test_mesh(devices: int = 8, axes=("data",)):
     return jax.make_mesh(shape, axes)
 
 
-# v5e per-chip hardware constants (roofline denominators)
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # B/s
-ICI_BW = 50e9                 # B/s per link (aggregate approximation)
-HBM_PER_CHIP = 16 * 1024**3   # bytes
+class ChipPeaks(NamedTuple):
+    """Published peaks of one chip (roofline denominators)."""
+
+    flops_bf16: float   # FLOP/s
+    hbm_bw: float       # HBM bytes/s
+    ici_bw: float       # chip-to-chip interconnect bytes/s, all links
+
+
+# Keyed by ``jax.Device.device_kind``.  "TPU v5 lite" is TPU v5e; source:
+# Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB of HBM
+# at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect.
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops_bf16=197e12, hbm_bw=819e9,
+                             ici_bw=1600e9 / 8),
+}
+V5E = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of one ``device_kind`` chip.  A kind with no published row is
+    an error — never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}") from None

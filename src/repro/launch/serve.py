@@ -91,6 +91,8 @@ def main(argv=None):
                     help="idle seconds before the shared scan parks")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     asyncio.run(_run(args))
 
 

@@ -287,13 +287,17 @@ class _Bank:
         # fresh and the jitted step reclaims the carry in-region
 
     def params(self) -> SlotParams:
+        # Copies: the step is dispatched asynchronously and attach/detach
+        # rewrite these rows right after, while on CPU ``jnp.asarray`` may
+        # alias a NumPy buffer — the in-flight step would read the new rows.
         # hv rides along only for having banks — classic banks keep the
         # 4-field params their jitted steps were traced with
-        hv = (jnp.asarray(self.hv) if self.name.endswith(":having")
-              else None)
-        return SlotParams(expr=jnp.asarray(self.expr),
-                          lo=jnp.asarray(self.lo), hi=jnp.asarray(self.hi),
-                          fresh=jnp.asarray(self.fresh), hv=hv)
+        def put(a):
+            return jnp.asarray(a.copy())
+
+        hv = put(self.hv) if self.name.endswith(":having") else None
+        return SlotParams(expr=put(self.expr), lo=put(self.lo),
+                          hi=put(self.hi), fresh=put(self.fresh), hv=hv)
 
 
 class SharedScan:
@@ -461,15 +465,23 @@ class QueryHandle:
         self._outcome: Optional[QueryOutcome] = None
         self._record: Optional[SlotRecord] = None
         self._cancelled = False
+        self._error: Optional[BaseException] = None
 
     @property
     def done(self) -> bool:
         return self._done.is_set()
 
     async def result(self) -> QueryOutcome:
+        """The outcome; raises the error of a scan step that failed."""
         await self._done.wait()
+        if self._error is not None:
+            raise self._error
         assert self._outcome is not None
         return self._outcome
+
+    def _fail(self, exc: BaseException) -> None:
+        self._error = exc
+        self._done.set()
 
     def _finish(self, rec: SlotRecord, d_total: float) -> None:
         est = (jax.device_get(rec.estimate)
@@ -621,6 +633,19 @@ class OLAService:
                     runner.handles.pop(id(rec), None)
                     handle._finish(rec, d_total)
 
+    def _fail_runner(self, runner: "_Runner", exc: Exception) -> None:
+        """A scan step raised: every query on that scan resolves with the
+        error, and the scan is dropped — the next submit starts afresh."""
+        waiting = [*runner.handles.values(),
+                   *(h for op, h in runner.pending if op == "attach")]
+        runner.handles.clear()
+        runner.pending.clear()
+        for key, r in list(self._runners.items()):
+            if r is runner:
+                del self._runners[key]
+        for handle in waiting:
+            handle._fail(exc)
+
     async def _drive(self, runner: "_Runner") -> None:
         loop = asyncio.get_running_loop()
         while True:
@@ -634,7 +659,11 @@ class OLAService:
                 except asyncio.TimeoutError:
                     return                # park: scan object stays warm
                 continue
-            progressed = await loop.run_in_executor(None, runner.scan.step)
+            try:
+                progressed = await loop.run_in_executor(None, runner.scan.step)
+            except Exception as exc:  # noqa: BLE001 - handed to every waiter
+                self._fail_runner(runner, exc)
+                return
             for rec, prog in progressed:
                 handle = runner.handles.get(id(rec))
                 if handle is None:

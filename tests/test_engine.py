@@ -98,3 +98,22 @@ def test_groupby_large_state_round_path(shards):
                               tpch.q1_group_large, 1000)
     np.testing.assert_allclose(np.asarray(res.final), exact, rtol=2e-3,
                                atol=1e-2)
+
+
+@pytest.mark.parametrize("emit,rounds", [("chunk", 1), ("chunk", 2),
+                                         ("round", 2)])
+def test_groupby_adds_chunk_partials_not_rows(emit, rounds):
+    """Each chunk's group partial is summed alone, then added to the carry:
+    chunk 1's 1024 ones sum to 1024 exactly and land on 2^24 exactly.  Were
+    the rows added straight into the carry, each +1 on 2^24 would round
+    away (f32 ulp 2 there), leaving 2^24."""
+    x = np.ones((1, 2, 1024), np.float32)
+    x[0, 0] = 0.0
+    x[0, 0, 0] = 2.0 ** 24
+    shards = {"x": jnp.asarray(x), "_mask": jnp.ones_like(jnp.asarray(x))}
+    g = gla.make_groupby_gla(
+        lambda c: c["x"], lambda c: jnp.ones_like(c["x"]),
+        lambda c: jnp.zeros(c["x"].shape, jnp.int32), num_groups=4,
+        d_total=2048.0)
+    res = engine.run_query(g, shards, rounds=rounds, emit=emit)
+    assert float(np.asarray(res.final)[0, 0]) == 2.0 ** 24 + 1024

@@ -104,8 +104,15 @@ def test_merge_associative_commutative(a, b, c):
     m = E.sum_state_merge
     left = m(m(s1, s2), s3)
     right = m(s1, m(s2, s3))
-    for x, y in zip(jax.tree.leaves(left), jax.tree.leaves(right)):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-5)
+    # Each grouping of a 3-term f32 sum is within γ_2·Σ|x| of the exact
+    # sum (tpch.f32_sum_tolerance), so the two are within 2·γ_2·Σ|x| of
+    # each other.  Relative to the result this is unbounded when the terms
+    # cancel (1.23 + 3.75 - 5.0), so the bound is on Σ|x|.
+    mag = jax.tree.map(lambda p, q, r: abs(p) + abs(q) + abs(r), s1, s2, s3)
+    tol = 2 * tpch.f32_sum_tolerance(2)
+    for x, y, s in zip(jax.tree.leaves(left), jax.tree.leaves(right),
+                       jax.tree.leaves(mag)):
+        assert abs(float(x) - float(y)) <= tol * float(s)
     ab, ba = m(s1, s2), m(s2, s1)
     for x, y in zip(jax.tree.leaves(ab), jax.tree.leaves(ba)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-6)

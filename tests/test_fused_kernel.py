@@ -1,8 +1,12 @@
 """Fused selection→bucket→aggregate kernel (kernels/fused_agg.py,
-DESIGN.md §12): bitwise equivalence against the segment-sum scan path
-across {scalar, group, bundle} × {plain, dict, bit-packed} on both
-engines, the dense-predicate regression that broke the legacy "bitwise"
-claim, MXU padding discipline, and single-dispatch accounting."""
+DESIGN.md §12), run in interpret mode: bitwise equivalence against the
+segment-sum scan path across {scalar, group, bundle} × {plain, dict,
+bit-packed} on both engines, the dense-predicate regression that broke
+the legacy "bitwise" claim, MXU padding discipline, blocked and ragged
+chunk grids, lowering refusals, and single-dispatch accounting.  (A
+compiled TPU kernel's group members take the one-hot contraction and
+are held to the float64-oracle tolerance instead — docs/KERNELS.md
+rule 2.)"""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -109,19 +113,23 @@ def _fold_scan(g, cols, rounds=ROUNDS):
 
 @pytest.mark.parametrize("name", ["scalar", "scalar4", "group", "bundle"])
 @pytest.mark.parametrize("encoding", ["plain", "encoded"])
-def test_fused_round_step_bitwise_vs_scan(shards, raw, name, encoding):
+@pytest.mark.parametrize("rounds", [ROUNDS, 1])
+def test_fused_round_step_bitwise_vs_scan(shards, raw, name, encoding,
+                                          rounds):
     """Carry-in fused steps == scan fold, every round boundary, every
-    member, bit for bit — including in-kernel dict + bit-packed decode."""
+    member, bit for bit — including in-kernel dict + bit-packed decode.
+    One round covers the whole 12-chunk shard: a full 8-chunk block plus
+    a partial one."""
     g = _glas()[name]
     cols = _flat_cols(shards)
     encs = _encodings(raw) if encoding == "encoded" else ()
     feed = _encode_cols(cols, encs) if encs else cols
 
-    ref_st, ref_views = _fold_scan(g, cols)
+    ref_st, ref_views = _fold_scan(g, cols, rounds)
     st = g.init()
     C = cols["_mask"].shape[0]
-    per = C // ROUNDS
-    for r in range(ROUNDS):
+    per = C // rounds
+    for r in range(rounds):
         st = SC.fused_round_step(
             g, st, {k: x[r * per:(r + 1) * per] for k, x in feed.items()},
             encs)
@@ -249,9 +257,9 @@ def test_fused_mxu_padding_spy(shards):
     seen = []
     orig = FK._chunk_contrib
 
-    def spy(fs, meta_row, chunk, msk, L):
+    def spy(fs, meta_row, chunk, msk, L, use_mxu):
         seen.append(meta_row)
-        out = orig(fs, meta_row, chunk, msk, L)
+        out = orig(fs, meta_row, chunk, msk, L, use_mxu)
         # contributions arrive already padded to the accumulator layout
         assert all(d.shape[1] % 8 == 0 or d.shape[1] == 1 for d in out)
         return out
@@ -302,6 +310,25 @@ def test_fused_single_dispatch_accounting(shards, raw):
         jax.eval_shape(lambda c: SC.fused_prefix_states(_glas()["scalar"], c),
                        cols)
     assert box[0] == 1
+
+
+def test_fused_lowering_error_names_plan(shards, raw):
+    """A compiled (non-interpret) kernel refuses, by name, the plans the
+    TPU compiler cannot lower — join probe gathers and bit-packed unpacks
+    — before any pallas_call is built; interpret mode runs them."""
+    cols = _flat_cols(shards)
+    join_cols, join_g, _ = tpch.q3_scenario(ROWS, num_orders=64)
+    del join_cols
+    join_feed = dict(cols, orderkey=jnp.zeros_like(cols["rfls"]))
+    with pytest.raises(FK.FusedLoweringError, match="probe"):
+        FK.fused_round_step(join_g, join_g.init(), join_feed,
+                            interpret=False)
+    g = _glas()["scalar"]
+    encs = _encodings(raw)
+    feed = _encode_cols(cols, encs)
+    with pytest.raises(FK.FusedLoweringError, match="bit-packed"):
+        FK.fused_prefix_states(g, feed, encs, interpret=False)
+    FK.fused_prefix_states(g, feed, encs, interpret=True)
 
 
 def test_fused_available_gates():

@@ -146,6 +146,26 @@ def test_detach_reattach_reuses_slot_without_recompile():
     assert _bits(renew.estimate.estimate, se.estimate)
 
 
+def test_slot_params_are_not_views_of_host_rows():
+    """A step is dispatched asynchronously, and the service detaches the
+    queries it completed right after — rewriting the bank's host rows.
+    The parameters the step holds must keep the rows they were built
+    from (on CPU ``jnp.asarray`` may alias a NumPy buffer)."""
+    fam, packed = _family(), _packed()
+    scan = SV.SharedScan(fam, packed, rounds=8)
+    recs = [scan.attach(G.SlotQuery("qty", {"discount": (0.0, 0.02 + i / 100)}))
+            for i in range(8)]
+    bank = scan.banks["scalar"]
+    for rec in recs:
+        p = bank.params()
+        rows = (p.expr, p.lo, p.hi, p.fresh)
+        before = [np.array(x) for x in rows]
+        scan.detach(rec)
+        bank.fresh[:] = ~bank.fresh
+        for x, b in zip(rows, before):
+            assert _bits(x, b)
+
+
 def test_churn_bounded_compiles_certified_by_audit():
     """The acceptance gate: compile count under arrival/departure churn
     is bounded by capacity doublings — asserted from the audit catalog,
@@ -232,6 +252,30 @@ def test_service_rejects_bad_submissions():
             with pytest.raises(ValueError):
                 # confidence is a compile-time static of the shared step
                 await svc.submit(QuerySpec(Q_SCALAR, confidence=0.5), packed)
+
+    asyncio.run(main())
+
+
+def test_failed_scan_step_fails_its_queries(monkeypatch):
+    """A scan step that raises resolves every query on that scan with the
+    error — none waits forever — and the next submit gets a fresh scan."""
+    fam, packed = _family(), _packed()
+
+    def broken_step(self):
+        raise RuntimeError("step failed")
+
+    async def main():
+        async with SV.OLAService(fam, rounds=8) as svc:
+            with monkeypatch.context() as m:
+                m.setattr(SV.SharedScan, "step", broken_step)
+                hs = [await svc.submit(q, packed)
+                      for q in (Q_SCALAR, Q_LATE)]
+                for h in hs:
+                    with pytest.raises(RuntimeError, match="step failed"):
+                        await asyncio.wait_for(h.result(), 60)
+            assert svc.scan_for(packed) is None
+            out = await (await svc.submit(Q_SCALAR, packed)).result()
+            assert out.rounds_witnessed == 8
 
     asyncio.run(main())
 
