@@ -38,6 +38,13 @@ slot ``fresh`` and the step resets its carry to the init state via
 ``jnp.where`` *inside* the jit region (no shape change, and no
 ``0 * x`` masking — that would turn negative carries into ``-0.0`` and
 break bitwise identity with a fresh query's ``+0.0`` init).
+
+Tracing: each phase of the drive records a ``jax.profiler.TraceAnnotation``
+named ``ola.*`` (``ola.slice``, ``ola.params``, ``ola.dispatch`` and
+``ola.stop_rule`` on the executor thread; ``ola.apply``, ``ola.finish``,
+``ola.idle``, ``ola.queued`` and ``ola.grow`` on the event-loop thread).
+Each is a leaf — none encloses a whole step — and costs about a
+microsecond when no profiler runs (DESIGN.md §11).
 """
 from __future__ import annotations
 
@@ -50,6 +57,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from repro.core import engine as EN
 from repro.core import scan as SC
@@ -182,7 +190,9 @@ class SlotRecord:
     witnessed: List[Tuple[int, int]] = dataclasses.field(default_factory=list)
     scanned: float = 0.0
     estimate: Any = None                  # latest per-round Estimate
-    elapsed_s: float = 0.0
+    query_id: int = -1                    # the service's id, for spans
+    attached_at: float = 0.0              # perf_counter() at attach
+    elapsed_s: float = 0.0                # attach -> latest progress, wall
     done: bool = False
     converged: bool = False               # stop rule fired (vs full pass)
     detached: bool = False
@@ -256,11 +266,13 @@ class _Bank:
                                           for _ in range(K))
         self.K = 2 * K
 
-    def attach(self, q: SlotQuery, stop) -> SlotRecord:
+    def attach(self, q: SlotQuery, stop, query_id: int) -> SlotRecord:
         try:
             k = self.slots.index(None)
         except ValueError:
-            self._grow()
+            # the bank's next dispatch compiles (or loads) its 2K program
+            with _span("ola.grow", bank=self.name, K=2 * self.K):
+                self._grow()
             k = self.slots.index(None)
         expr_idx, lo, hi = self.family.slot_row(q)
         self.expr[k] = expr_idx
@@ -269,7 +281,8 @@ class _Bank:
         self.fresh[k] = True
         self.generation[k] += 1
         rec = SlotRecord(query=q, bank=self.name, slot=k,
-                         generation=int(self.generation[k]), stop=stop)
+                         generation=int(self.generation[k]), stop=stop,
+                         query_id=query_id, attached_at=time.perf_counter())
         self.slots[k] = rec
         return rec
 
@@ -343,14 +356,15 @@ class SharedScan:
     def active_slots(self) -> int:
         return sum(b.active for b in self.banks.values())
 
-    def attach(self, q: SlotQuery, stop=None) -> SlotRecord:
+    def attach(self, q: SlotQuery, stop=None,
+               query_id: int = -1) -> SlotRecord:
         name = self.family.bank_of(q)
         bank = self.banks.get(name)
         if bank is None:
             bank = self.banks[name] = _Bank(name, self.family, self.P,
                                             mesh=self.mesh,
                                             axis_name=self.axis_name)
-        return bank.attach(q, stop)
+        return bank.attach(q, stop, query_id)
 
     def detach(self, rec: SlotRecord) -> None:
         bank = self.banks.get(rec.bank)
@@ -381,44 +395,52 @@ class SharedScan:
         the (record, progress) of each slot that witnessed the round.
         Completed slots come back with ``done`` set — the caller (the
         service) detaches them."""
-        t0 = time.perf_counter()
-        r = self.cursor % self.rounds
+        step = self.cursor
+        r = step % self.rounds
         lo, hi = r * self.width, (r + 1) * self.width
         live = {n: b for n, b in self.banks.items() if b.active}
         if not live:
             return []
-        slice_shards = self._slice(lo, hi)
-        range_count = float(self._ms[:, lo:hi].sum())
+        with _span("ola.slice", step=step):
+            slice_shards = self._slice(lo, hi)
+            range_count = float(self._ms[:, lo:hi].sum())
         out: List[Tuple[SlotRecord, RoundProgress]] = []
         for name, bank in live.items():
-            params = bank.params()
-            if self.mesh is None:
-                new_states, est = serve_step_vmapped(
-                    self.family, name, params, bank.states, slice_shards,
-                    self._w_r, self._d_local, self._d_total,
-                    confidence=self.confidence)
-            else:
-                new_states, est = serve_step_sharded(
-                    self.family, name, params, bank.states, slice_shards,
-                    self._w_r, self._d_local, self._d_total, mesh=self.mesh,
-                    axis_name=self.axis_name, confidence=self.confidence)
+            with _span("ola.params", step=step, bank=name, K=bank.K):
+                params = bank.params()
+            with _span("ola.dispatch", step=step, bank=name, K=bank.K):
+                if self.mesh is None:
+                    new_states, est = serve_step_vmapped(
+                        self.family, name, params, bank.states,
+                        slice_shards, self._w_r, self._d_local,
+                        self._d_total, confidence=self.confidence)
+                else:
+                    new_states, est = serve_step_sharded(
+                        self.family, name, params, bank.states,
+                        slice_shards, self._w_r, self._d_local,
+                        self._d_total, mesh=self.mesh,
+                        axis_name=self.axis_name,
+                        confidence=self.confidence)
             bank.states = new_states
             bank.fresh[:] = False
             bank.stepped_ks.add(bank.K)
-            dt = time.perf_counter() - t0
             for k, rec in enumerate(bank.slots):
                 if rec is None:
                     continue
                 rec.witnessed.append((lo, hi))
                 rec.scanned += range_count
                 rec.estimate = est[k]
-                rec.elapsed_s += dt
+                rec.elapsed_s = time.perf_counter() - rec.attached_at
                 prog = RoundProgress(
                     round=len(rec.witnessed), rounds_total=self.rounds,
                     estimates=est[k], scanned=rec.scanned,
                     d_total=float(self._d_total), elapsed_s=rec.elapsed_s)
-                if rec.stop is not None and rec.stop(prog):
-                    rec.converged = True
+                if rec.stop is not None:
+                    # the first rule to read an estimate waits on the device
+                    with _span("ola.stop_rule", step=step,
+                               query=rec.query_id):
+                        if rec.stop(prog):
+                            rec.converged = True
                 if rec.converged or len(rec.witnessed) >= self.rounds:
                     rec.done = True
                 out.append((rec, prog))
@@ -451,15 +473,17 @@ class QueryOutcome:
     scanned: float
     d_total: float
     converged: bool               # stop rule fired (False = full pass)
-    elapsed_s: float
+    elapsed_s: float              # host wall seconds, attach -> last round
 
 
 class QueryHandle:
     """An in-flight serving query: progress stream + awaitable result."""
 
-    def __init__(self, query: SlotQuery, stop):
+    def __init__(self, query: SlotQuery, stop, query_id: int):
         self.query = query
+        self.query_id = query_id
         self._stop = stop
+        self._queued: Optional[_span] = None   # open submit -> attach
         self.progress: List[RoundProgress] = []
         self._done = asyncio.Event()
         self._outcome: Optional[QueryOutcome] = None
@@ -478,6 +502,12 @@ class QueryHandle:
             raise self._error
         assert self._outcome is not None
         return self._outcome
+
+    def _dequeue(self) -> None:
+        """Close the ``ola.queued`` span (attach, cancel, failure, close)."""
+        if self._queued is not None:
+            self._queued.__exit__(None, None, None)
+            self._queued = None
 
     def _fail(self, exc: BaseException) -> None:
         self._error = exc
@@ -522,6 +552,7 @@ class OLAService:
         self.axis_name = axis_name
         self._runners: Dict[tuple, "_Runner"] = {}
         self._closed = False
+        self._next_query = 0          # per-service query ids, for spans
 
     # -- public surface -----------------------------------------------------
 
@@ -560,7 +591,10 @@ class OLAService:
                               confidence=self.confidence, mesh=self.mesh,
                               axis_name=self.axis_name)
             runner = self._runners[key] = _Runner(scan)
-        handle = QueryHandle(query, stop)
+        handle = QueryHandle(query, stop, self._next_query)
+        self._next_query += 1
+        handle._queued = _span("ola.queued", query=handle.query_id)
+        handle._queued.__enter__()
         runner.pending.append(("attach", handle))
         runner.wake.set()
         if runner.task is None or runner.task.done():
@@ -605,6 +639,9 @@ class OLAService:
                 await t
             except asyncio.CancelledError:
                 pass
+        for r in self._runners.values():
+            for _, handle in r.pending:
+                handle._dequeue()
 
     async def __aenter__(self) -> "OLAService":
         return self
@@ -615,23 +652,32 @@ class OLAService:
     # -- the drive loop -----------------------------------------------------
 
     def _apply_pending(self, runner: "_Runner") -> None:
+        if not runner.pending:
+            return
         pending, runner.pending = runner.pending, []
-        d_total = float(runner.scan._d_total)
-        for op, handle in pending:
-            if op == "attach":
-                if handle._cancelled:
-                    handle._finish(SlotRecord(handle.query, "", -1, 0),
-                                   d_total)
-                    continue
-                rec = runner.scan.attach(handle.query, handle._stop)
-                handle._record = rec
-                runner.handles[id(rec)] = handle
-            else:  # detach
-                rec = handle._record
-                if rec is not None and not rec.detached:
-                    runner.scan.detach(rec)
-                    runner.handles.pop(id(rec), None)
-                    handle._finish(rec, d_total)
+        counts = {}
+        if _span.is_enabled():            # no work for spans nobody records
+            n = sum(op == "attach" for op, _ in pending)
+            counts = dict(attaches=n, detaches=len(pending) - n)
+        with _span("ola.apply", **counts):
+            d_total = float(runner.scan._d_total)
+            for op, handle in pending:
+                if op == "attach":
+                    handle._dequeue()
+                    if handle._cancelled:
+                        handle._finish(SlotRecord(handle.query, "", -1, 0),
+                                       d_total)
+                        continue
+                    rec = runner.scan.attach(handle.query, handle._stop,
+                                             handle.query_id)
+                    handle._record = rec
+                    runner.handles[id(rec)] = handle
+                else:  # detach
+                    rec = handle._record
+                    if rec is not None and not rec.detached:
+                        runner.scan.detach(rec)
+                        runner.handles.pop(id(rec), None)
+                        handle._finish(rec, d_total)
 
     def _fail_runner(self, runner: "_Runner", exc: Exception) -> None:
         """A scan step raised: every query on that scan resolves with the
@@ -644,6 +690,7 @@ class OLAService:
             if r is runner:
                 del self._runners[key]
         for handle in waiting:
+            handle._dequeue()
             handle._fail(exc)
 
     async def _drive(self, runner: "_Runner") -> None:
@@ -655,8 +702,14 @@ class OLAService:
                 if runner.pending:
                     continue
                 try:
-                    await asyncio.wait_for(runner.wake.wait(), self.grace_s)
+                    with _span("ola.idle"):
+                        await asyncio.wait_for(runner.wake.wait(),
+                                               self.grace_s)
                 except asyncio.TimeoutError:
+                    # a submit in the timeout's own event-loop turn found
+                    # this task still running and started no other
+                    if runner.pending:
+                        continue
                     return                # park: scan object stays warm
                 continue
             try:
@@ -664,15 +717,19 @@ class OLAService:
             except Exception as exc:  # noqa: BLE001 - handed to every waiter
                 self._fail_runner(runner, exc)
                 return
-            for rec, prog in progressed:
-                handle = runner.handles.get(id(rec))
-                if handle is None:
-                    continue
-                handle.progress.append(prog)
-                if rec.done:
-                    runner.scan.detach(rec)
-                    runner.handles.pop(id(rec), None)
-                    handle._finish(rec, float(runner.scan._d_total))
+            with _span("ola.finish") as span:
+                for rec, prog in progressed:
+                    handle = runner.handles.get(id(rec))
+                    if handle is None:
+                        continue
+                    handle.progress.append(prog)
+                    if rec.done:
+                        runner.scan.detach(rec)
+                        runner.handles.pop(id(rec), None)
+                        handle._finish(rec, float(runner.scan._d_total))
+                if _span.is_enabled():
+                    span.set_metadata(
+                        finished=sum(rec.done for rec, _ in progressed))
             # yield so submit()/cancel() callbacks enqueue between steps
             await asyncio.sleep(0)
 

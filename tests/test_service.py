@@ -12,10 +12,13 @@ The load-bearing claims, each proven here:
     (``bounded_compiles_under_churn``);
   * the asyncio service converges queries via their stop rules, parks an
     idle scan after the grace period, and un-parks it on the next submit
-    without losing the cursor.
+    without losing the cursor;
+  * a served query's ``elapsed_s`` is wall time since its attach, and a
+    submit that races the grace timeout is still served.
 """
 import asyncio
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
@@ -231,6 +234,81 @@ def test_service_converge_park_unpark():
             assert o3.rounds_witnessed > 0
             # same scan object kept its cursor across the park
             assert svc.scan_for(packed).steps_done > steps_before
+
+    asyncio.run(main())
+
+
+def test_served_elapsed_is_wall_time_since_attach():
+    """``elapsed_s`` counts host wall time from the attach, so a time
+    budget means on a served query what it means on a Session: the
+    stop rule of another bank, stepped after this query's, counts too."""
+    fam, packed = _family(), _packed()
+    nap = 0.05
+
+    def slow(prog):
+        time.sleep(nap)
+        return False
+
+    async def main():
+        async with SV.OLAService(fam, rounds=8) as svc:
+            # compile both banks' steps first: a compile is no stop rule
+            warm = [await svc.submit(
+                QuerySpec(q, stop=lambda prog: prog.round >= 1), packed)
+                for q in (Q_SCALAR, Q_GROUP)]
+            for h in warm:
+                await h.result()
+            timed = await svc.submit(
+                QuerySpec(Q_SCALAR, stop=SN.budget(max_seconds=3 * nap)),
+                packed)
+            other = await svc.submit(QuerySpec(Q_GROUP, stop=slow), packed)
+            out = await timed.result()
+            await other.result()
+            return out, timed.progress
+
+    out, progress = asyncio.run(main())
+    assert out.converged and out.rounds_witnessed < 8
+    assert out.elapsed_s == progress[-1].elapsed_s >= 3 * nap
+    # between two rounds of the budgeted query, the slow rule ran once
+    steps = [b.elapsed_s - a.elapsed_s for a, b in zip(progress, progress[1:])]
+    assert steps and min(steps) >= nap
+
+
+def test_submit_racing_the_grace_timeout_is_served(monkeypatch):
+    """A submit in the same event-loop turn as the idle scan's grace
+    timeout finds the drive task still running and starts no other; the
+    task must look at its queue again before it parks."""
+    fam, packed = _family(), _packed()
+    real_wait_for = asyncio.wait_for
+    raced = []
+
+    async def main():
+        async with SV.OLAService(fam, rounds=8, grace_s=0.05) as svc:
+            async def racing(aw, timeout):
+                if raced:
+                    return await real_wait_for(aw, timeout)
+                aw.close()
+                # the submit runs after the timeout fired and before the
+                # drive task resumes from it
+                submit = svc.submit(Q_SCALAR, packed)
+                try:
+                    submit.send(None)
+                except StopIteration as done:
+                    raced.append(done.value)
+                raise asyncio.TimeoutError
+
+            monkeypatch.setattr(SV.asyncio, "wait_for", racing)
+            first = await svc.submit(
+                QuerySpec(Q_SCALAR, stop=lambda prog: prog.round >= 1),
+                packed)
+            await first.result()
+            for _ in range(1000):
+                if raced:
+                    break
+                await asyncio.sleep(0.01)
+            served, _ = await asyncio.wait(
+                {asyncio.ensure_future(raced[0].result())}, timeout=20)
+            assert served, "the racing submit was never served"
+            assert served.pop().result().rounds_witnessed == 8
 
     asyncio.run(main())
 
