@@ -7,8 +7,9 @@ suppkeys folded into 2**13 hash buckets) through both engine group-by
 implementations:
 
   * ``emit="round"``  — the scan path: one ``jax.ops.segment_sum`` per
-    state field per chunk (XLA's CPU scatter expander turns each into a
-    per-item update loop; on TPU it is a sorted-segment / one-hot lowering).
+    state field per chunk (2**13 buckets is above ``gla.ONEHOT_MAX_GROUPS``;
+    XLA's CPU scatter expander turns each into a per-item update loop, and
+    a v5e runs it as a serialized scatter).
   * ``emit="kernel"`` — the Pallas path: ONE fused
     selection→bucket→aggregate dispatch per round-slice of each shard
     (``repro/kernels/fused_agg.py``, DESIGN.md §12; the GLA publishes a
@@ -35,9 +36,8 @@ chunk-by-chunk in the scan's association order).
 Wall-time caveat: on this CPU the kernel runs in Pallas *interpret* mode,
 which materializes the [block, G] one-hot densely — so segment_sum wins
 wall time here.  The dispatch counts and the flop/byte terms are the
-platform-independent mechanism: on TPU the one-hot contraction is the MXU
-lowering segment_sum itself resolves to, minus the per-chunk dispatch and
-state-emission overhead (DESIGN.md §3).
+platform-independent mechanism: on TPU the one-hot contraction runs on the
+MXU where the scan path's segment_sum runs as a scatter (DESIGN.md §3).
 
 Output: CSV (name,us_per_call,derived) to stdout + benchmarks/out/
 BENCH_groupby.json (schema in benchmarks/README.md).

@@ -12,18 +12,19 @@ Queries are expressed as ``func(chunk) -> [n] or [n, A]`` (A simultaneous
 aggregates, like TPC-H Q1's four SUMs) and ``cond(chunk) -> [n] in {0,1}``.
 Group-by adds ``group(chunk) -> [n] int ids in [0, num_groups)``.
 
-TPU adaptation (DESIGN.md §3): the per-group scatter is a
-``jax.ops.segment_sum`` here (lowers to one-hot matmul / sorted segment ops on
-TPU); the Pallas hot-path kernel in ``repro/kernels`` implements the identical
-contraction with explicit VMEM tiling and is allclose-checked against these
-reference semantics.  Group-by GLAs publish the ``(vals, weight, gids)``
-kernel projection so ``engine.run_query(emit="kernel")`` reaches that kernel
-directly (one dispatch per round-slice); large raw-id domains fold through
+TPU adaptation (DESIGN.md §3): a chunk's per-group partials are a one-hot
+contraction at ``Precision.HIGHEST`` when the static group count is at most
+:data:`ONEHOT_MAX_GROUPS`, and a ``jax.ops.segment_sum`` scatter above it
+(:func:`group_partials`); the Pallas hot-path kernels in ``repro/kernels``
+implement the same contraction with explicit VMEM tiling.  Group-by GLAs
+publish the ``(vals, weight, gids)`` kernel projection so
+``engine.run_query(emit="kernel")`` reaches that kernel directly (one
+dispatch per round-slice); large raw-id domains fold through
 :func:`hash_bucket` into a 2**bucket_bits dense bucket table.
 """
 from __future__ import annotations
 
-from functools import lru_cache
+from functools import lru_cache, partial
 from typing import Callable, Mapping, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -290,6 +291,50 @@ def make_sum_gla(
 # Paper Alg. 3 — GLAGroupBy (composite GLA: a GLASum per group)
 # ---------------------------------------------------------------------------
 
+# Largest static group count whose chunk partials take the one-hot
+# contraction: the MXU's lane width, where a 1024-row chunk's [L, G] f32
+# one-hot is 512 KiB.  A v5e runs segment_sum as a serialized scatter
+# (~9 ns a row per partial); larger domains, such as 2**13 hash buckets,
+# whose one-hot would be 32 MiB a chunk, keep it.
+ONEHOT_MAX_GROUPS = 128
+
+
+def group_partials_path(num_groups: int) -> str:
+    """How a chunk's group partials lower for a static group count:
+    ``"onehot"`` (a contraction) or ``"scatter"`` (``segment_sum``)."""
+    return "onehot" if num_groups <= ONEHOT_MAX_GROUPS else "scatter"
+
+
+def group_partials(vals, w, gids, num_groups: int):
+    """One chunk's per-group (sum, sumsq, matched) partials.
+
+    ``vals`` [n, A], ``w`` [n] weights, ``gids`` [n] int32.  Ids outside
+    ``[0, num_groups)`` are dropped on both paths: ``segment_sum`` drops
+    them, and they equal no column of the one-hot's iota.  The one-hot
+    dots run at ``Precision.HIGHEST`` — at default precision a TPU rounds
+    the f32 operands to bfloat16.  The barrier keeps each chunk's [G, A]
+    partial a value of its own: without it XLA folds
+    ``carry + scatter_add(0, …)`` into ``scatter_add(carry, …)``, which adds
+    every row straight into the running carry — f32 error then grows with
+    the rows scanned, not with the chunks (4.7e-4 relative at 2^25 rows per
+    partition).
+    """
+    vw = vals * w[:, None]
+    if group_partials_path(num_groups) == "onehot":
+        onehot = (jax.lax.broadcasted_iota(
+            jnp.int32, (gids.shape[0], num_groups), 1)
+            == gids[:, None]).astype(vals.dtype)                # [n, G]
+        dot = partial(jnp.dot, preferred_element_type=vals.dtype,
+                      precision=jax.lax.Precision.HIGHEST)
+        parts = (dot(onehot.T, vw), dot(onehot.T, vals * vw),
+                 dot(onehot.T, w[:, None])[:, 0])
+    else:
+        parts = (jax.ops.segment_sum(vw, gids, num_segments=num_groups),
+                 jax.ops.segment_sum(vals * vw, gids, num_segments=num_groups),
+                 jax.ops.segment_sum(w, gids, num_segments=num_groups))
+    return jax.lax.optimization_barrier(parts)
+
+
 def make_groupby_gla(
     func: Callable[[Chunk], jnp.ndarray],
     cond: Callable[[Chunk], jnp.ndarray],
@@ -307,7 +352,9 @@ def make_groupby_gla(
     State is the dense composite of per-group GLASum states ("GLA
     composition", paper §4.4): sums/sumsqs/matched are [G, A]/[G]; ``scanned``
     is global (each group's predicate is cond ∧ group==g over the same scan).
-    The per-item scatter is a segment_sum → one-hot MXU contraction on TPU.
+    A chunk's per-group partials come from :func:`group_partials`: a
+    one-hot contraction up to :data:`ONEHOT_MAX_GROUPS` groups, a
+    ``segment_sum`` scatter above.
 
     ``bucket_bits`` enables the large-domain hash-bucketed group table
     (paper's 1M-group Q1): raw ids from ``group`` are folded through
@@ -344,16 +391,7 @@ def make_groupby_gla(
         vals = _as_2d(func(chunk)).astype(dtype)             # [n, A]
         w = (cond(chunk) * chunk["_mask"]).astype(dtype)     # [n]
         gids = group(chunk).astype(jnp.int32)                # [n]
-        vw = vals * w[:, None]
-        # The barrier keeps each chunk's [G, A] partial a value of its own:
-        # without it XLA folds `carry + scatter_add(0, …)` into
-        # `scatter_add(carry, …)`, which adds every row straight into the
-        # running carry — f32 error then grows with the rows scanned, not
-        # with the chunks (4.7e-4 relative at 2^25 rows per partition).
-        d_s, d_q, d_m = jax.lax.optimization_barrier((
-            jax.ops.segment_sum(vw, gids, num_segments=G),
-            jax.ops.segment_sum(vals * vw, gids, num_segments=G),
-            jax.ops.segment_sum(w, gids, num_segments=G)))
+        d_s, d_q, d_m = group_partials(vals, w, gids, G)
         return E.SumState(
             sum=state.sum + d_s,
             sumsq=state.sumsq + d_q,
@@ -815,6 +853,13 @@ class SlotFamily:
             hv = None if params.hv is None else params.hv[k]
             members.append(self._member_gla(bank, func, cond, d_total, hv))
         return _combine_members(tuple(members), f"slots-{bank}x{K}")
+
+    def partials_path(self, bank: str) -> str:
+        """How the bank's chunk partials lower: ``"none"`` for the scalar
+        bank, else :func:`group_partials_path` of its group count."""
+        if bank == "scalar":
+            return "none"
+        return group_partials_path(self.groups[bank.partition(":")[0]][1])
 
     def zero_slot_state(self, bank: str):
         """One slot's init state (the reclaim target of a fresh slot)."""

@@ -25,10 +25,12 @@ Equality (docs/KERNELS.md rule 2):
   * scalar members repeat ``gla.acc_sum``'s expression tree per chunk, so
     interpret-mode (CPU) states are bitwise-identical to the scan path;
   * group members scatter with ``jax.ops.segment_sum`` in interpret mode
-    (the scan's own tree) and with a one-hot MXU contraction in compiled
-    TPU kernels, where scatter does not lower.  The contraction
-    re-associates the per-group sums, so on TPU the contract is the
-    written float64-oracle tolerance, not bitwise.
+    and with a one-hot MXU contraction in compiled TPU kernels, where
+    scatter does not lower.  The scan path takes the HIGHEST one-hot dot
+    up to ``gla.ONEHOT_MAX_GROUPS`` groups, ``segment_sum`` above; on
+    XLA:CPU the two agree bitwise at chunk lengths up to 256.  On TPU the
+    kernel's contract is the written float64-oracle tolerance, not
+    bitwise.
 
 Bundles fuse further: all members' accumulations run in the SAME
 ``pallas_call`` (separate out-ref triples per member), so N concurrent
@@ -213,12 +215,13 @@ def _chunk_contrib(fs, meta_row, chunk, msk, L, use_mxu):
     surrounding scan carries), so its states are bitwise-identical to the
     scan path on every backend that shares the expression tree.
 
-    Group members scatter with the scan path's ``jax.ops.segment_sum``
-    unless ``use_mxu``, which takes the one-hot MXU contraction instead —
-    the compiled TPU lowering, where a scatter does not lower.  The
-    contraction reduces over L in a different association, so its low
-    bits differ from the scatter's: its contract is the float64-oracle
-    tolerance (docs/KERNELS.md rule 2).
+    Group members scatter with ``jax.ops.segment_sum`` unless ``use_mxu``,
+    which takes the one-hot MXU contraction instead — the compiled TPU
+    lowering, where a scatter does not lower.  The scan path's
+    ``gla.group_partials`` takes the same HIGHEST dot up to
+    ``gla.ONEHOT_MAX_GROUPS`` groups; on XLA:CPU the scatter and the dot
+    agree bitwise at L ≤ 256.  A compiled kernel's group members are held
+    to the float64-oracle tolerance (docs/KERNELS.md rule 2).
 
     Reductions run over the UNPADDED [L, A] values / [G, A] segments —
     padding A (or G) first changes the reduce's vectorization, hence its
@@ -388,13 +391,14 @@ def fused_round_step(gla, state, cols, encodings=(), *, interpret=None,
     :class:`FusedLoweringError`).
 
     ``use_mxu`` picks the group members' accumulation: the one-hot MXU
-    contraction (True) or the scan's segment_sum scatter (False).  It
+    contraction (True) or the segment_sum scatter (False).  It
     defaults to the compile target — one-hot in a compiled kernel, where
     scatter does not lower, scatter under interpret mode.
 
-    Equality: with the scatter, identical to folding ``gla.accumulate``
-    over the C chunks (``scan.scan_round_step``), including from a
-    checkpointed mid-scan carry; scalar members are identical either way.
+    Equality: with the scatter, identical on XLA:CPU (chunk lengths up to
+    256) to folding ``gla.accumulate`` over the C chunks
+    (``scan.scan_round_step``), including from a checkpointed mid-scan
+    carry; scalar members are identical either way.
     ``scanned`` (and nothing else) is accumulated outside the kernel —
     live counts are integer-valued f32, exact under any association, and
     need only ``_mask``.

@@ -21,9 +21,9 @@ lane-dim column — tolerated, and sliced off by the wrapper anyway).
 
 Bitwise guarantee: driven with ``block_rows`` == chunk length (as
 ``core/scan.py::kernel_round_delta`` does), accumulation runs chunk by
-chunk in the scan's association order and states equal the segment_sum
-scan bit-for-bit.  The fused round-slice kernel
-(:mod:`repro.kernels.fused_agg`, DESIGN.md §12) extends the same
+chunk in the scan's association order and states equal the group-by
+scan's (``gla.group_partials``) bit-for-bit on XLA:CPU.  The fused
+round-slice kernel (:mod:`repro.kernels.fused_agg`, DESIGN.md §12) extends the same
 guarantee to scalars and in-kernel decode; authoring rules in
 docs/KERNELS.md.
 """

@@ -128,7 +128,8 @@ def group_agg(vals, weight, gids, *, num_groups: int, block_rows: int = 512,
 
     Bitwise guarantee: with ``block_rows`` pinned to the chunk length the
     kernel adds per-chunk contributions in the scan's association order,
-    so round states and finals equal the segment_sum scan bit-for-bit
+    so round states and finals equal the group-by scan's
+    (``gla.group_partials``) bit-for-bit on XLA:CPU
     (tests/test_groupby_kernel.py, docs/KERNELS.md §2/§6).
     """
     interpret = _interpret_default() if interpret is None else interpret

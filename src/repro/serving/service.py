@@ -211,6 +211,7 @@ class _Bank:
                  mesh=None, axis_name: str = "data"):
         self.name = name
         self.family = family
+        self.partials = family.partials_path(name)   # ola.dispatch metadata
         self.P = P
         self.mesh = mesh
         self.axis_name = axis_name
@@ -408,7 +409,8 @@ class SharedScan:
         for name, bank in live.items():
             with _span("ola.params", step=step, bank=name, K=bank.K):
                 params = bank.params()
-            with _span("ola.dispatch", step=step, bank=name, K=bank.K):
+            with _span("ola.dispatch", step=step, bank=name, K=bank.K,
+                       partials=bank.partials):
                 if self.mesh is None:
                     new_states, est = serve_step_vmapped(
                         self.family, name, params, bank.states,

@@ -125,6 +125,9 @@ def test_service_records_one_leaf_span_per_phase(tmp_path):
     assert sum(s[2]["detaches"] for s in spans["ola.apply"]) == 1
     assert count["ola.apply"] >= 2 and count["ola.idle"] >= 1
     assert {s[2]["K"] for s in spans["ola.dispatch"]} == {1, 2}
+    # the group bank (G = 4) takes the one-hot partials; the scalar none
+    assert {(s[2]["bank"], s[2]["partials"]) for s in spans["ola.dispatch"]} \
+        == {("scalar", "none"), ("rfls", "onehot")}
 
     # leaves: the executor's spans never overlap one another, and no
     # loop-thread phase spans a step's start
